@@ -1,11 +1,1 @@
-"""repro — paper reproduction grown toward a production jax system.
-
-Importing the package installs small forward-compat adapters for the pinned
-jax version (see :mod:`repro._jax_compat`) so that all modules — and the
-subprocess scripts the distributed tests spawn — can use the modern
-``jax.shard_map`` / ``jax.make_mesh(axis_types=...)`` surface uniformly.
-"""
-
-from repro import _jax_compat
-
-_jax_compat.install()
+"""repro — paper reproduction grown toward a production jax system."""

@@ -39,8 +39,7 @@ def main(argv=None):
         print(f"unknown command {cmd!r}; options: {', '.join(_COMMANDS)}",
               file=sys.stderr)
         return 2
-    # import late: repro.launch.dryrun must set XLA_FLAGS before jax
-    # initializes its backend, and the other CLIs defer jax themselves.
+    # import late: each CLI defers jax (and any XLA_FLAGS it adds) to main
     import importlib
 
     mod = importlib.import_module(_COMMANDS[cmd])

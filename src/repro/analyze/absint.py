@@ -87,7 +87,7 @@ class _Scope:
 
     ``alias`` maps an inlined sub-jaxpr's invar to the outer var it was
     bound to, so producer chases (select_n predicate refinement, the
-    max-sub idiom) cross pjit/remat/custom_* boundaries instead of dying
+    max-sub idiom) cross jit/remat/custom_* boundaries instead of dying
     at the first wrapper ``jnp.where`` emits.
     """
 
@@ -282,7 +282,7 @@ class _Interp:
             return self._cond(eqn, vals)
         subs = _jaxpr_params(eqn)
         if subs:
-            # pjit / shard_map / remat / custom_*: inline into the SAME
+            # jit / shard_map / remat / custom_*: inline into the SAME
             # scope with invar aliases so provenance (guards, max-sub)
             # survives the wrapper jnp.where/jnp.clip emit around bodies
             out = None

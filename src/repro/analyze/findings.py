@@ -73,7 +73,7 @@ def source_key(source_info) -> tuple[str, str]:
 
     try:
         from jax._src import source_info_util
-        fr = source_info_util.user_frame(source_info)
+        fr = source_info_util.user_frame(source_info.traceback)
     except Exception:
         fr = None
     if fr is None:

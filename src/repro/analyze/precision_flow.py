@@ -19,7 +19,7 @@ The walk also checks integer ``psum`` accumulators: summing ``n`` clients'
 (:func:`repro.dist.collectives.wire_dtype`); anything narrower overflows
 on the wire.
 
-Sub-jaxprs (scan/while/cond/pjit/shard_map/remat/custom_*) are entered
+Sub-jaxprs (scan/while/cond/jit/shard_map/remat/custom_*) are entered
 with taint mapped across their invars; loop carries iterate to a fixpoint
 before findings are collected, so a dequant inside a scanned layer body is
 reported exactly once.
@@ -39,14 +39,6 @@ _PROPAGATE = frozenset({
     "all_gather", "copy", "rev", "concatenate", "pad", "stop_gradient",
     "optimization_barrier",
 })
-
-# eqn params that hold sub-jaxprs entered with invars mapped 1:1
-_ONE_TO_ONE_SUBJAXPR_PRIMS = frozenset({
-    "pjit", "closed_call", "core_call", "remat", "remat2", "checkpoint",
-    "custom_jvp_call", "custom_vjp_call", "custom_vjp_call_jaxpr",
-    "shard_map", "scan",
-})
-
 
 def _is_var(v) -> bool:
     """True for jaxpr Vars (hashable); Literals carry ``.val``."""
@@ -127,8 +119,7 @@ class _Walker:
 
         if prim == "pallas_call":
             # the fast path itself: codes are consumed INSIDE the kernel
-            name = str(eqn.params.get("name_and_src_info", ""))
-            if "quant_matmul" in name:
+            if eqn.params.get("name") == "quant_matmul":
                 self.n_fastpath += 1
             return
 
@@ -232,7 +223,7 @@ class _Walker:
                 res = self.run(_inner(br), in_taint[1:])
                 out_taint = [a or b for a, b in zip(out_taint, res)]
         else:
-            # pjit / shard_map / remat / custom_* and any unknown primitive
+            # jit / shard_map / remat / custom_* and any unknown primitive
             # whose sub-jaxpr invars align 1:1 with the eqn's
             for _, sj in subs:
                 sub = _inner(sj)

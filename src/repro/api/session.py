@@ -254,6 +254,32 @@ class Session:
         return init_fn(key if key is not None
                        else jax.random.PRNGKey(self.spec.seed))
 
+    @functools.cached_property
+    def serving_params(self):
+        """The serving weights: :meth:`init_params` packed to the policy's
+        storage (norm/router exemptions as in training).
+
+        Init and pack are one jitted program, so each f32 leaf is a
+        transient of that program and the full f32 tree is never resident:
+        a published-width model whose f32 tree exceeds device memory fits
+        once packed.
+        """
+        import jax
+
+        from repro.core.quantization import default_exempt
+        from repro.launch.steps import build_init_fn
+        from repro.models.common import pack_params_for_policy
+
+        init_fn, _ = build_init_fn(self.model, self.mesh, self.axes)
+        policy = self.policy
+
+        def init_and_pack(key):
+            return pack_params_for_policy(init_fn(key), policy,
+                                          jax.random.PRNGKey(1),
+                                          exempt=default_exempt)
+
+        return jax.jit(init_and_pack)(jax.random.PRNGKey(self.spec.seed))
+
     def train_step(self, opt=None, *, attn_impl: str = "auto",
                    donate: bool = False):
         """Policy-driven :class:`~repro.launch.steps.TrainStep` builder."""
@@ -505,13 +531,11 @@ class Session:
         import jax
         import jax.numpy as jnp
 
-        from repro.core.quantization import default_exempt
         from repro.launch.paging import (SlotPager, kv_cache_bytes,
                                          pages_for, plan_admissions,
                                          set_page_tables)
         from repro.launch.steps import (
             build_cached_prefill, build_decode_step, init_global_caches)
-        from repro.models.common import pack_params_for_policy
 
         spec, policy = self.spec, self.policy
         o = dict(spec.options)
@@ -572,15 +596,12 @@ class Session:
             page_size = next(p for p in (16, 8, 4, 2, 1) if s_max % p == 0)
         page_size = int(page_size)
 
-        params = self.init_params()
-
-        # ---- pack to the policy's storage (norm/router exemptions as in
-        # training) ------------------------------------------------------
-        raw_bytes = _weight_bytes(params)
-        f32_bytes = sum(x.size * 4 for x in jax.tree_util.tree_leaves(params))
+        # ---- weights, packed to the policy's storage ---------------------
+        raw = jax.eval_shape(self.init_params, jax.random.PRNGKey(0))
+        raw_bytes = _weight_bytes(raw)
+        f32_bytes = sum(x.size * 4 for x in jax.tree_util.tree_leaves(raw))
         serve_bits = policy.serve_bits
-        qparams = pack_params_for_policy(params, policy, jax.random.PRNGKey(1),
-                                         exempt=default_exempt)
+        qparams = self.serving_params
         q_bytes = _weight_bytes(qparams)
         if policy.packed:
             say(f"params: {raw_bytes/1e6:.1f} MB f32 -> {q_bytes/1e6:.1f} MB "
@@ -858,7 +879,7 @@ class Session:
         )
         say(f"decoded {stats.decoded_tokens} tokens over {stats.decode_steps} "
             f"steps x {batch} slots in {wall:.3f}s = {stats.tok_s:.1f} tok/s "
-            f"(interpret-mode numbers off-TPU)")
+            f"on {jax.devices()[0].platform}")
         say(f"admitted {stats.admitted} / completed {stats.completed} sequences "
             f"(continuous batching over {n_requests} requests; "
             f"{capacity_stops} capacity stops, "

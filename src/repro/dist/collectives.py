@@ -37,10 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import _jax_compat
 from repro.core.quantization import FULL_PRECISION_BITS, _sr_round
-
-_jax_compat.install()
 
 
 def _axis_size(names: tuple[str, ...]) -> int:

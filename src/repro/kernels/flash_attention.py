@@ -135,6 +135,7 @@ def flash_attention_kernel(q, k, v, *, causal: bool = True,
             pltpu.VMEM((bq, D), jnp.float32),   # output accumulator
         ],
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)
 
 
@@ -174,10 +175,9 @@ def attention_spec(BH: int, S: int, D: int, *,
 
 def _decode_body(pt_ref, len_ref, q_ref, k_ref, v_ref, acc_out, m_out, l_out,
                  m_ref, l_ref, acc_ref, *, scale: float, page: int,
-                 n_pmax: int):
+                 n_pmax: int, n_kv: int):
     b = pl.program_id(0)
-    h = pl.program_id(1)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -189,31 +189,35 @@ def _decode_body(pt_ref, len_ref, q_ref, k_ref, v_ref, acc_out, m_out, l_out,
     # the index map clamps -1 to page 0 for the DMA, but the compute guard
     # means that page's contents are never read into the softmax.
     pid = pt_ref[b * n_pmax + j]
-    valid = jnp.logical_and(pid >= 0, j * page < len_ref[b])
+    n_valid = len_ref[b]
 
-    @pl.when(valid)
+    @pl.when(jnp.logical_and(pid >= 0, j * page < n_valid))
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale          # (G, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)               # (page, hd)
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # (G, page)
-        cols = j * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(cols < len_ref[b], s, _NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        # one DMA brings every KV head of the page; heads are walked with
+        # static indices (KV is small), each a (G, hd) x (page, hd) problem
+        for h in range(n_kv):
+            q = q_ref[0, h].astype(jnp.float32) * scale           # (G, hd)
+            k = k_ref[0, :, h, :].astype(jnp.float32)             # (page, hd)
+            v = v_ref[0, :, h, :].astype(jnp.float32)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            cols = j * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(cols < n_valid, s, _NEG_INF)           # (G, page)
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
 
     @pl.when(j == n_pmax - 1)
     def _finish():
-        acc_out[0, 0] = acc_ref[...]
-        m_out[0, 0] = m_ref[...]
-        l_out[0, 0] = l_ref[...]
+        acc_out[0] = acc_ref[...]
+        m_out[0] = m_ref[...]
+        l_out[0] = l_ref[...]
 
 
 def _decode_maps(n_pmax: int):
@@ -225,11 +229,11 @@ def _decode_maps(n_pmax: int):
     validity guard keeps that page's contents out of the softmax.
     """
 
-    def q_map(b, h, j, pt, ln):
-        return (b, h, 0, 0)
+    def q_map(b, j, pt, ln):
+        return (b, 0, 0, 0)
 
-    def kv_map(b, h, j, pt, ln):
-        return (jnp.maximum(pt[b * n_pmax + j], 0), 0, h, 0)
+    def kv_map(b, j, pt, ln):
+        return (jnp.maximum(pt[b * n_pmax + j], 0), 0, 0, 0)
 
     return q_map, kv_map
 
@@ -244,10 +248,13 @@ def flash_decode_kernel(q, k_pages, v_pages, page_table, lengths, *,
     ``page_table``: (B, n_pmax) int32, -1 = unallocated.
     ``lengths``: (B,) int32 — valid tokens per slot in local coordinates.
 
-    Grid is (B, KV, n_pmax) with the page axis innermost (sequential on TPU,
-    so the online-softmax scratch carries across a slot's pages); the page
+    Grid is (B, n_pmax) with the page axis innermost (sequential on TPU, so
+    the online-softmax scratch carries across a slot's pages); the page
     table and lengths are scalar-prefetched so each k/v BlockSpec can DMA the
-    pool row the table names.  Returns UNNORMALIZED fp32 partials
+    pool row the table names.  A k/v block is one whole page, all KV heads
+    included: its trailing (KV, hd) dims equal the pool's, which is what the
+    TPU lowering requires of a block whose KV extent is below the sublane
+    tile.  Returns UNNORMALIZED fp32 partials
     ``(acc (B,KV,G,hd), m (B,KV,G,1), l (B,KV,G,1))`` — normalize with
     ``acc / max(l, eps)``, or pmax/psum-merge across sequence-parallel shards
     first.
@@ -259,31 +266,32 @@ def flash_decode_kernel(q, k_pages, v_pages, page_table, lengths, *,
     q_map, kv_map = _decode_maps(n_pmax)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, KV, n_pmax),
+        grid=(B, n_pmax),
         in_specs=[
-            pl.BlockSpec((1, 1, G, hd), q_map),
-            pl.BlockSpec((1, page, 1, hd), kv_map),
-            pl.BlockSpec((1, page, 1, hd), kv_map),
+            pl.BlockSpec((1, KV, G, hd), q_map),
+            pl.BlockSpec((1, page, KV, hd), kv_map),
+            pl.BlockSpec((1, page, KV, hd), kv_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, G, hd), q_map),
-            pl.BlockSpec((1, 1, G, 1), q_map),
-            pl.BlockSpec((1, 1, G, 1), q_map),
+            pl.BlockSpec((1, KV, G, hd), q_map),
+            pl.BlockSpec((1, KV, G, 1), q_map),
+            pl.BlockSpec((1, KV, G, 1), q_map),
         ],
         scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),    # running max
-            pltpu.VMEM((G, 1), jnp.float32),    # running sum
-            pltpu.VMEM((G, hd), jnp.float32),   # output accumulator
+            pltpu.VMEM((KV, G, 1), jnp.float32),    # running max
+            pltpu.VMEM((KV, G, 1), jnp.float32),    # running sum
+            pltpu.VMEM((KV, G, hd), jnp.float32),   # output accumulator
         ],
     )
     return pl.pallas_call(
         functools.partial(_decode_body, scale=scale, page=page,
-                          n_pmax=n_pmax),
+                          n_pmax=n_pmax, n_kv=KV),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, KV, G, hd), jnp.float32),
                    jax.ShapeDtypeStruct((B, KV, G, 1), jnp.float32),
                    jax.ShapeDtypeStruct((B, KV, G, 1), jnp.float32)],
         interpret=interpret,
+        name="flash_decode",
     )(page_table.reshape(-1), lengths, q, k_pages, v_pages)
 
 
@@ -307,9 +315,9 @@ def decode_spec(B: int, KV: int, G: int, hd: int, *, page: int, n_pool: int,
     q_map, kv_map = _decode_maps(n_pmax)
 
     def _bind(m):
-        return lambda b, h, j: m(b, h, j, pt_flat, ln)
+        return lambda b, j: m(b, j, pt_flat, ln)
 
-    grid = (B, KV, n_pmax)
+    grid = (B, n_pmax)
     # pool rows are addressed through the table: repeated / skipped rows are
     # legal, so the k/v pools check OOB only ("any" coverage)
     return KernelSpec(
@@ -317,21 +325,21 @@ def decode_spec(B: int, KV: int, G: int, hd: int, *, page: int, n_pool: int,
         source="flash_attention.py:flash_decode_kernel",
         grid=grid,
         inputs=(
-            BlockOperand("q", (B, KV, G, hd), (1, 1, G, hd), _bind(q_map)),
+            BlockOperand("q", (B, KV, G, hd), (1, KV, G, hd), _bind(q_map)),
             BlockOperand("k_pages", (n_pool, page, KV, hd),
-                         (1, page, 1, hd), _bind(kv_map), coverage="any"),
+                         (1, page, KV, hd), _bind(kv_map), coverage="any"),
             BlockOperand("v_pages", (n_pool, page, KV, hd),
-                         (1, page, 1, hd), _bind(kv_map), coverage="any"),
+                         (1, page, KV, hd), _bind(kv_map), coverage="any"),
         ),
         outputs=(
-            BlockOperand("acc", (B, KV, G, hd), (1, 1, G, hd), _bind(q_map)),
-            BlockOperand("m", (B, KV, G, 1), (1, 1, G, 1), _bind(q_map)),
-            BlockOperand("l", (B, KV, G, 1), (1, 1, G, 1), _bind(q_map)),
+            BlockOperand("acc", (B, KV, G, hd), (1, KV, G, hd), _bind(q_map)),
+            BlockOperand("m", (B, KV, G, 1), (1, KV, G, 1), _bind(q_map)),
+            BlockOperand("l", (B, KV, G, 1), (1, KV, G, 1), _bind(q_map)),
         ),
         scratch=(
-            ScratchSpec("m_run", (G, 1), "float32"),
-            ScratchSpec("l_run", (G, 1), "float32"),
-            ScratchSpec("acc_run", (G, hd), "float32", binds="acc"),
+            ScratchSpec("m_run", (KV, G, 1), "float32"),
+            ScratchSpec("l_run", (KV, G, 1), "float32"),
+            ScratchSpec("acc_run", (KV, G, hd), "float32", binds="acc"),
         ),
         # the scalar-prefetch contract: kv_map clamps -1 to page 0 and the
         # compute guard masks it, so -1 is legal; anything >= n_pool would

@@ -101,6 +101,7 @@ def quant_matmul_kernel(x, codes, scale, *, blocks=DEFAULT_BLOCKS,
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="quant_matmul",
     )(x, codes, scale)
 
 

@@ -55,3 +55,27 @@ def flash_attention_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         s = jnp.where(mask[None, None], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32)).astype(q.dtype)
+
+
+def flash_decode_ref(q: jnp.ndarray, k_pages: jnp.ndarray,
+                     v_pages: jnp.ndarray, page_table: jnp.ndarray,
+                     lengths: jnp.ndarray) -> jnp.ndarray:
+    """One decode token per slot against a paged KV cache, normalized.
+
+    q: (B, KV, G, hd); pools (N_pool, page, KV, hd); page_table (B, n_pmax)
+    with -1 for unallocated pages; lengths (B,) valid tokens per slot.
+    Gathers each slot's pages into a contiguous view and takes a full
+    masked softmax.  Returns (B, KV, G, hd) f32.
+    """
+    B, KV, G, hd = q.shape
+    page = k_pages.shape[1]
+    n_pmax = page_table.shape[1]
+    pids = jnp.maximum(page_table, 0)
+    k = k_pages[pids].reshape(B, n_pmax * page, KV, hd).astype(jnp.float32)
+    v = v_pages[pids].reshape(B, n_pmax * page, KV, hd).astype(jnp.float32)
+    s = jnp.einsum("bhgd,bshd->bhgs", q.astype(jnp.float32), k) * hd ** -0.5
+    valid = ((jnp.arange(n_pmax * page)[None, :] < lengths[:, None])
+             & jnp.repeat(page_table >= 0, page, axis=1))
+    s = jnp.where(valid[:, None, None, :], s, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhgs,bshd->bhgd", p, v)

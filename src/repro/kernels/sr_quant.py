@@ -66,6 +66,7 @@ def sr_quant_fake_kernel(w, u, step, *, block=DEFAULT_BLOCK, interpret=False):
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct(w.shape, w.dtype),
         interpret=interpret,
+        name="sr_quant_fake",
     )(w, u, step)
 
 
@@ -81,4 +82,5 @@ def sr_quant_pack_kernel(w, u, step, *, bits: int = 7, block=DEFAULT_BLOCK,
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct(w.shape, jnp.int8),
         interpret=interpret,
+        name="sr_quant_pack",
     )(w, u, step)

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run CLI (deliverable e) — a thin shim over
 :meth:`repro.api.Session.run_dryrun`.
 
@@ -17,6 +14,7 @@ Usage:
 
 import argparse
 import json
+import os
 import traceback
 
 
@@ -52,6 +50,15 @@ def main(argv=None):
     ap.add_argument("--serve-bits", type=int, default=0)
     ap.add_argument("--no-remat", action="store_true")
     args = ap.parse_args(argv)
+    from repro.launch.mesh import enable_compile_cache
+
+    enable_compile_cache()
+    # the multi-pod mesh needs 512 fake host devices; XLA reads the flag when
+    # the backend starts, so add it (to whatever the caller set) before that
+    flags = os.environ.get("XLA_FLAGS", "")
+    if "xla_force_host_platform_device_count" not in flags:
+        os.environ["XLA_FLAGS"] = (
+            f"{flags} --xla_force_host_platform_device_count=512").strip()
 
     from repro.api import PrecisionPolicy
     from repro.configs import ARCH_NAMES, get_config, shapes_for

@@ -44,6 +44,9 @@ def main(argv=None):
     ap.add_argument("--ckpt-every", type=int, default=10)
     ap.add_argument("--out", default="")
     args = ap.parse_args(argv)
+    from repro.launch.mesh import enable_compile_cache
+
+    enable_compile_cache()
 
     from repro.api import RunSpec, Session
 

@@ -10,9 +10,31 @@ multi-pod: 2x16x16 = 512 — the leading ``pod`` axis extends data parallelism
 
 from __future__ import annotations
 
+import os
+import pathlib
+
 import jax
 
 from repro.dist.collectives import AxisCtx
+
+#: The persistent compilation cache's home when the environment names none:
+#: a fixed directory in the checkout (git-ignored).  The path is part of
+#: the cache key, so it must not move between runs.
+COMPILE_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on jax's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, wins and nothing is changed
+    (jax reads it itself); otherwise the cache goes to
+    :data:`COMPILE_CACHE_DIR`.  Call before the first compile.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    return str(COMPILE_CACHE_DIR)
 
 _AXES_FOR_RANK = {1: ("data",), 2: ("data", "model"), 3: ("pod", "data", "model")}
 

@@ -108,6 +108,10 @@ def main(argv=None):
                     "pools demote f32 -> bf16 under pool pressure, e.g. "
                     '\'{"kind": "constant", "kv_watermark": 0.9}\'')
     args = ap.parse_args(argv)
+    from repro.launch.mesh import enable_compile_cache
+
+    enable_compile_cache()
+
     program = None
     if args.precision_program:
         import json

@@ -340,7 +340,8 @@ def build_cached_prefill(model: Model, mesh, axes: AxisCtx, *,
                          policy=None,
                          bos_id: int = 1, page_size: int | None = None,
                          pool_pages: int | None = None,
-                         with_prompt_lens: bool = False):
+                         with_prompt_lens: bool = False,
+                         with_logits: bool = False):
     """Prefill-into-slots step for continuous batching.
 
     The jitted fn signature is ``(params, batch, caches, slot_mask) ->
@@ -358,6 +359,9 @@ def build_cached_prefill(model: Model, mesh, axes: AxisCtx, *,
     ``prompt_lens (B,)`` argument — prompts right-padded to the ``s_prompt``
     bucket keep their true per-slot lengths (cache stamps, last-position
     logits), which is what makes one compiled prefill serve a whole bucket.
+    ``with_logits=True`` also returns each slot's last-position logits
+    ``(B, 1, V)`` between the token and the caches, for checking one
+    implementation of the prefill against another.
     """
     cfg = model.cfg
     tp = _size(mesh, axes.model_axis)
@@ -378,7 +382,10 @@ def build_cached_prefill(model: Model, mesh, axes: AxisCtx, *,
             tok = jnp.full((b_local, 1), bos_id, jnp.int32)
         else:
             tok = _greedy_pick(axes, tp, vl, logits)
-        return tok, merge_slot_caches(caches, filled, slot_mask)
+        merged = merge_slot_caches(caches, filled, slot_mask)
+        if with_logits:
+            return tok, logits.astype(jnp.float32), merged
+        return tok, merged
 
     if params_tree is None:
         params_tree = jax.eval_shape(
@@ -400,8 +407,12 @@ def build_cached_prefill(model: Model, mesh, axes: AxisCtx, *,
     in_specs = [param_specs, bspecs, c_specs, mask_spec]
     if with_prompt_lens:
         in_specs.append(mask_spec)          # (B,) int32, same batch sharding
+    out_specs = (tok_spec, c_specs)
+    if with_logits:                         # vocab-parallel local logits
+        out_specs = (tok_spec, P(tok_spec[0], None, axes.model_axis),
+                     c_specs)
     sm = jax.shard_map(local_prefill, mesh=mesh, in_specs=tuple(in_specs),
-                       out_specs=(tok_spec, c_specs), check_vma=False)
+                       out_specs=out_specs, check_vma=False)
     return ServeStep(fn=jax.jit(sm), param_specs=param_specs, cache_specs=c_specs,
                      param_shapes=params_tree, caches_shape=caches_shape)
 

@@ -39,6 +39,9 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default="")
     args = ap.parse_args(argv)
+    from repro.launch.mesh import enable_compile_cache
+
+    enable_compile_cache()
 
     from repro.api import PrecisionPolicy, RunSpec, Session
 

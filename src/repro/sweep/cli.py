@@ -16,30 +16,7 @@ point and re-run: completed cells are skipped by content hash.
 from __future__ import annotations
 
 import argparse
-import os
 import sys
-
-
-def _force_device_count(n: int) -> None:
-    """Pin the fake-device-count XLA flag before jax initializes.
-
-    Must run before any jax backend query; replaces an inherited value (CI
-    exports an 8-device flag for the test suite) with the sweep's own.
-    """
-    from repro.sweep.runner import _drop_device_count_flag
-
-    flags = _drop_device_count_flag(os.environ.get("XLA_FLAGS", ""))
-    os.environ["XLA_FLAGS"] = (
-        f"{flags} --xla_force_host_platform_device_count={n}").strip()
-
-
-def _inproc_device_need(sweep) -> int:
-    """Fake host devices the sweep's IN-PROCESS cells need (subprocess
-    cells pin their own count; see runner._run_subprocess)."""
-    from repro.sweep.runner import SUBPROCESS_WORKLOADS, _mesh_devices
-
-    return max([_mesh_devices(c.spec.mesh) for c in sweep.cells()
-                if c.spec.workload not in SUBPROCESS_WORKLOADS] + [1])
 
 
 def main(argv=None) -> int:
@@ -73,10 +50,6 @@ def main(argv=None) -> int:
         return 0
 
     sweep = get_preset(args.preset)
-    if args.cmd == "run":
-        need = _inproc_device_need(sweep)
-        if need > 1:
-            _force_device_count(need)
     from repro.sweep.report import write_experiments
     from repro.sweep.runner import ResultsStore, SweepRunner
 

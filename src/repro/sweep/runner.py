@@ -2,13 +2,12 @@
 
 Execution model
 ---------------
-* ``dryrun`` and ``fl-sim`` cells run **in-process** (AOT lowering and the
-  vmap simulator are cheap to host and share jax warm-up across cells).
-* ``serve`` / ``train`` / ``fl-orchestrate`` cells run in a **subprocess
-  with a timeout** (``python -m repro.sweep.runner --one``): the decode
-  driver and the pod trainer hold compiled executables and donated buffers
-  that should not accumulate across a grid, and a wedged cell must not
-  wedge the sweep.
+Every cell runs in a **subprocess with a timeout** (``python -m
+repro.sweep.runner --one``) and the runner itself never touches a jax
+backend.  On an accelerator host a backend belongs to one process at a
+time, so a parent that had initialized jax would hold the chip its cells
+need.  The child also owns its fake host device count on CPU, drops its
+compiled executables when it exits, and cannot wedge the sweep.
 
 Resumability
 ------------
@@ -32,9 +31,6 @@ import time
 
 from repro.api.spec import RunSpec
 from repro.sweep.grid import Sweep
-
-#: Workloads isolated in a subprocess (with timeout) rather than in-process.
-SUBPROCESS_WORKLOADS = ("serve", "train", "fl-orchestrate")
 
 
 def git_sha() -> str:
@@ -165,7 +161,6 @@ class SweepRunner:
     sweep: Sweep
     store: ResultsStore
     timeout_s: float = 1800.0
-    subprocess_workloads: tuple = SUBPROCESS_WORKLOADS
     quiet: bool = False
 
     def _say(self, msg: str) -> None:
@@ -211,21 +206,7 @@ class SweepRunner:
         t0 = time.time()
         base = {"key": cell.key, "sweep": cell.sweep,
                 "spec": cell.spec.to_dict(), "git_sha": git_sha()}
-        try:
-            if cell.spec.workload in self.subprocess_workloads:
-                status, metrics = self._run_subprocess(cell)
-            else:
-                status, metrics = "ok", execute_cell(cell.spec)
-        except Exception as e:                      # noqa: BLE001
-            # an in-process cell crash becomes an explicit failed row (with
-            # enough traceback to diagnose), never a dead grid: later cells
-            # still run, and a resumed sweep can deterministically skip or
-            # retry this key (rerun_failed)
-            import traceback
-
-            status = "error"
-            metrics = {"error": f"{type(e).__name__}: {e}",
-                       "traceback": traceback.format_exc()[-2000:]}
+        status, metrics = self._run_subprocess(cell)
         if isinstance(metrics, dict) and metrics.get("status") == "FAIL":
             status = "error"
         return {**base, "status": status, "metrics": metrics,
@@ -260,8 +241,16 @@ class SweepRunner:
                 return "timeout", {"timeout_s": self.timeout_s,
                                    "stderr": stderr[-2000:]}
             if proc.returncode != 0 or not os.path.exists(out_path):
+                # a crashed cell becomes an explicit failed row with enough
+                # of its traceback to diagnose, never a dead grid: later
+                # cells still run, and a resumed sweep can skip or retry
+                # this key (rerun_failed)
+                tail = proc.stderr[-2000:]
+                lines = tail.strip().splitlines()
                 return "error", {"returncode": proc.returncode,
-                                 "stderr": proc.stderr[-2000:]}
+                                 "stderr": tail,
+                                 "error": lines[-1] if lines else "",
+                                 "traceback": tail}
             with open(out_path) as f:
                 return "ok", json.load(f)
 
@@ -308,6 +297,9 @@ def _one_main(argv=None) -> int:
     ap.add_argument("--one", required=True)
     ap.add_argument("--out", required=True)
     args = ap.parse_args(argv)
+    from repro.launch.mesh import enable_compile_cache
+
+    enable_compile_cache()
     with open(args.one) as f:
         spec = RunSpec.from_dict(json.load(f))
     metrics = execute_cell(spec)

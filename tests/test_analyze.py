@@ -11,7 +11,6 @@ import os
 import numpy as np
 import pytest
 
-import repro  # noqa: F401  (installs the jax compat shims)
 import jax
 import jax.numpy as jnp
 

@@ -18,7 +18,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config, smoke_variant
-from repro.kernels import ops
+from repro.kernels import ops, ref
 from repro.launch.mesh import axis_ctx_for, make_test_mesh
 from repro.launch.paging import (
     PagePool, SlotPager, plan_admissions, set_page_tables)
@@ -389,24 +389,6 @@ class TestPagedFamilies:
 
 
 class TestFlashDecodeKernel:
-    def _reference(self, q, kp, vp, pt, lens, page):
-        B, KV, G, hd = q.shape
-        n_pmax = pt.shape[1]
-        kv = np.asarray(kp)[np.maximum(pt, 0)].reshape(B, n_pmax * page, KV, hd)
-        vv = np.asarray(vp)[np.maximum(pt, 0)].reshape(B, n_pmax * page, KV, hd)
-        alloc = np.repeat(pt >= 0, page, axis=1)
-        out = np.zeros((B, KV, G, hd), np.float32)
-        for b in range(B):
-            for h in range(KV):
-                s = (np.asarray(q)[b, h].astype(np.float32)
-                     @ kv[b, :, h].astype(np.float32).T) * hd ** -0.5
-                mask = (np.arange(n_pmax * page) < lens[b]) & alloc[b]
-                s = np.where(mask[None, :], s, -1e30)
-                w = np.exp(s - s.max(-1, keepdims=True))
-                w /= w.sum(-1, keepdims=True)
-                out[b, h] = w @ vv[b, :, h].astype(np.float32)
-        return out
-
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     def test_matches_gathered_softmax(self, dtype):
         """Kernel output == gathered-contiguous softmax oracle, for both
@@ -425,8 +407,9 @@ class TestFlashDecodeKernel:
                                            vp.astype(dtype),
                                            jnp.asarray(pt), jnp.asarray(lens))
         got = np.asarray(acc / np.maximum(np.asarray(l), 1e-30))
-        want = self._reference(q, kp.astype(dtype), vp.astype(dtype),
-                               pt, lens, page)
+        want = np.asarray(ref.flash_decode_ref(
+            q, kp.astype(dtype), vp.astype(dtype), jnp.asarray(pt),
+            jnp.asarray(lens)))
         tol = 1e-5 if dtype == jnp.float32 else 3e-2
         np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
 

@@ -1,0 +1,9 @@
+"""The benchmark's tests import ``chipbench`` from the checkout root."""
+
+import pathlib
+import sys
+
+ROOT = pathlib.Path(__file__).resolve().parents[2]
+for p in (ROOT, ROOT / "src"):
+    if str(p) not in sys.path:
+        sys.path.insert(0, str(p))

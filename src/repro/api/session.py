@@ -39,6 +39,7 @@ bit-widths costs K compiles, not one per round.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import json
@@ -47,6 +48,7 @@ import time
 
 import numpy as np
 
+from repro import obs
 from repro.api.precision import PrecisionPolicy
 from repro.api.spec import RunSpec, SIM_ARCHS
 
@@ -528,6 +530,14 @@ class Session:
         buckets so one compiled prefill serves every prompt length in the
         bucket (``vary_prompt`` draws ragged prompt lengths).
         """
+        # ``repro.serve.prepare`` spans the entry to the decode loop's start:
+        # ``_serve`` closes the stack there, an exception on its way out
+        with contextlib.ExitStack() as prepare:
+            prepare.enter_context(obs.span("repro.serve.prepare"))
+            return self._serve(prepare, overrides)
+
+    def _serve(self, prepare: contextlib.ExitStack,
+               overrides: dict) -> ServeStats:
         import jax
         import jax.numpy as jnp
 
@@ -718,12 +728,24 @@ class Session:
         admitted = completed = decoded = 0
         capacity_stops = 0
         deferred_ids: set = set()   # requests that waited at least once
+        waves = 0
+
+        def call(sp, program: str, step, *args):
+            """``step.fn(*args)`` inside span ``sp``.  A call that adds an
+            entry to the step's jit cache traced its program and loaded it
+            from the compilation cache: ``sp`` then carries
+            ``load=program``."""
+            known = step.loads()
+            out = step.fn(*args)
+            if step.loads() > known:
+                sp.set(load=program)
+            return out
 
         def req_cap(req):
             return min(req["prompt_len"] + req["max_new"], s_max)
 
         def admit():
-            nonlocal caches, cur_tok, admitted, pool_pressure
+            nonlocal waves, pool_pressure
             free = [i for i in range(batch) if not active[i]]
             fill = []
             if pager is None:
@@ -761,6 +783,12 @@ class Session:
                 pool_pressure = 1.0 if blocked else pager.pool.pressure
             if not fill:
                 return
+            waves += 1
+            with obs.span("repro.serve.admit", wave=waves):
+                prefill_wave(fill)
+
+        def prefill_wave(fill):
+            nonlocal caches, cur_tok, admitted
             if pager is not None:
                 caches = set_page_tables(caches, pager.table)
             new_tok = np.array(cur_tok)
@@ -775,11 +803,15 @@ class Session:
                 for s, req in group:
                     mask[s] = True
                     plens[s] = len(req["prompt"])
-                tok, caches_new = pf.fn(qparams, prefill_batch(group, bucket),
-                                        caches, jnp.asarray(mask),
-                                        jnp.asarray(plens))
-                caches = caches_new
-                tok = np.asarray(tok)
+                inputs = prefill_batch(group, bucket)
+                # space-separated: TraceMe metadata splits its args at commas
+                ids = " ".join(str(req["id"]) for _, req in group)
+                with obs.span("repro.serve.prefill", bucket=bucket,
+                              requests=ids) as sp:
+                    tok, caches = call(sp, f"serve_prefill_{bucket}", pf,
+                                       qparams, inputs, caches,
+                                       jnp.asarray(mask), jnp.asarray(plens))
+                    tok = np.asarray(tok)
                 for s, req in group:
                     active[s] = True
                     remaining[s] = req["max_new"]
@@ -814,51 +846,60 @@ class Session:
         admit()
         maybe_demote_kv()
         # first call compiles; its output is a real decode step, consumed below
-        tok, caches = ss.fn(qparams, {"token": cur_tok}, caches)
-        tok_h = np.asarray(tok)               # sync: compile finishes here
-        t0, step_i, decoded_at_t0 = time.time(), 1, 0
+        with obs.span("repro.serve.step", step=1) as sp:
+            tok, caches = call(sp, "serve_decode", ss, qparams,
+                               {"token": cur_tok}, caches)
+            tok_h = np.asarray(tok)           # sync: compile finishes here
+        prepare.close()
+        t0, step_i, decoded_at_t0 = time.perf_counter(), 1, 0
         while True:
-            done_any = False
-            for s in range(batch):
-                if not active[s]:
-                    continue
-                seqs[s].append(int(tok_h[s, 0]))
-                decoded += 1
-                remaining[s] -= 1
-                # tokens cached so far (the newest token is not written until
-                # it is fed back)
-                cached = slot_plen[s] + len(seqs[s]) - 1
-                done = remaining[s] <= 0
-                if not done and cached >= slot_cap[s]:
-                    # cache full: STOP the slot — decoding on would drop K/V
-                    # writes and silently degrade the context (the old
-                    # driver's failure mode)
-                    done = True
-                    capacity_stops += 1
-                if done:
-                    active[s] = False
-                    if pager is not None:
-                        pager.evict(s)
-                    finished.append(seqs[s])
-                    completed += 1
-                    done_any = True
-            if step_i == 1:
-                decoded_at_t0 = decoded       # step 1 ran pre-timer (compile)
-            if step_i >= steps or (not active.any() and not queue):
+            with obs.span("repro.serve.slots", step=step_i):
+                done_any = False
+                for s in range(batch):
+                    if not active[s]:
+                        continue
+                    seqs[s].append(int(tok_h[s, 0]))
+                    decoded += 1
+                    remaining[s] -= 1
+                    # tokens cached so far (the newest token is not written
+                    # until it is fed back)
+                    cached = slot_plen[s] + len(seqs[s]) - 1
+                    done = remaining[s] <= 0
+                    if not done and cached >= slot_cap[s]:
+                        # cache full: STOP the slot — decoding on would drop
+                        # K/V writes and silently degrade the context (the
+                        # old serve loop's failure mode)
+                        done = True
+                        capacity_stops += 1
+                    if done:
+                        active[s] = False
+                        if pager is not None:
+                            pager.evict(s)
+                        finished.append(seqs[s])
+                        completed += 1
+                        done_any = True
+                if step_i == 1:
+                    decoded_at_t0 = decoded   # step 1 ran pre-timer (compile)
+                stop = step_i >= steps or (not active.any() and not queue)
+                if not stop:
+                    if done_any and pager is not None:
+                        # cleared table rows make the evicted slots' future
+                        # writes drop instead of landing on reclaimed pages
+                        caches = set_page_tables(caches, pager.table)
+                    # each slot feeds its own last token
+                    cur_tok = jnp.asarray(tok_h)
+            if stop:
                 break
-            if done_any and pager is not None:
-                # cleared table rows make the evicted slots' future writes
-                # drop instead of landing on reclaimed pages
-                caches = set_page_tables(caches, pager.table)
-            cur_tok = jnp.asarray(tok_h)      # each slot feeds its own last token
             if done_any and queue:
                 admit()                       # mid-flight slot reuse: overwrites
                                               # the admitted slots in cur_tok
                 maybe_demote_kv()
-            tok, caches = ss.fn(qparams, {"token": cur_tok}, caches)
-            tok_h = np.asarray(tok)
             step_i += 1
-        wall = time.time() - t0
+            with obs.span("repro.serve.step", step=step_i) as sp:
+                tok, caches = call(sp, "serve_decode", ss, qparams,
+                                   {"token": cur_tok}, caches)
+                tok_h = np.asarray(tok)
+        wall = time.perf_counter() - t0
 
         stats = ServeStats(
             arch=self.spec.arch, bits=serve_bits, attn_impl=attn_impl,

@@ -25,11 +25,13 @@ def _finish(data: PrimalData, q: np.ndarray, name: str) -> GBDResult:
     if not sol.feasible:
         return GBDResult(q=q, bandwidth=None, t_rounds=None, energy=np.inf,
                          lower_bound=np.inf, gap=0.0, iterations=1,
-                         converged=False, trace=[{"scheme": name, "feasible": False}])
+                         converged=False, trace=[{"scheme": name, "feasible": False}],
+                         primal_sweeps=sol.sweeps)
     return GBDResult(q=q, bandwidth=sol.bandwidth, t_rounds=sol.t_rounds,
                      energy=sol.value, lower_bound=sol.value, gap=0.0,
                      iterations=1, converged=True,
-                     trace=[{"scheme": name, "feasible": True}])
+                     trace=[{"scheme": name, "feasible": True}],
+                     primal_sweeps=sol.sweeps)
 
 
 def full_precision(data: PrimalData, spec: MasterSpec) -> GBDResult:

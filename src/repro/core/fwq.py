@@ -84,7 +84,7 @@ def make_fwq_round(
 ):
     """Build the jittable FWQ round function.
 
-    Returns ``round_fn(params, opt_state, batch, delta, rng) ->
+    Returns ``fl_round(params, opt_state, batch, delta, rng) ->
     (params, opt_state, FWQMetrics)`` where
 
     * ``batch``  — pytree whose leaves have leading dim ``n_clients``
@@ -103,7 +103,7 @@ def make_fwq_round(
         gsq = sum(jnp.vdot(g, g).real for g in jax.tree_util.tree_leaves(grads))
         return loss, grads, gsq
 
-    def round_fn(params, opt_state, batch, delta, rng):
+    def fl_round(params, opt_state, batch, delta, rng):
         n = delta.shape[0]  # cohort size from the data: elastic across rounds
         client_keys = jax.vmap(lambda i: jax.random.fold_in(rng, i))(jnp.arange(n))
         losses, grads, gsqs = jax.vmap(
@@ -125,7 +125,7 @@ def make_fwq_round(
         )
         return params, opt_state, metrics
 
-    return round_fn
+    return fl_round
 
 
 def make_fwq_client_grads(client_loss_fn: ClientLossFn):

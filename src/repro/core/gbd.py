@@ -24,6 +24,7 @@ from typing import Callable
 
 import numpy as np
 
+from repro import obs
 from repro.core.master import Cut, MasterSpec, MasterSolution, solve_master
 from repro.core.primal import (
     PrimalData,
@@ -47,6 +48,7 @@ class GBDResult:
     iterations: int
     converged: bool
     trace: list                  # per-iteration dicts (UB, LB, q)
+    primal_sweeps: int = 0       # water-filling sweeps over every primal
 
 
 def run_gbd(
@@ -68,6 +70,7 @@ def run_gbd(
     seed, so a still-good incumbent converges in one or two cuts.
     """
     cuts: list[Cut] = []
+    sweeps = 0
     ub = np.inf
     lb = -np.inf
     best: tuple[np.ndarray, PrimalSolution] | None = None
@@ -99,7 +102,9 @@ def run_gbd(
     z = 0
     converged = False
     for z in range(1, max_rounds + 1):
-        sol = solve_primal(data, q)
+        with obs.span("repro.gbd.primal", iter=z):
+            sol = solve_primal(data, q)
+        sweeps += sol.sweeps
         if sol.feasible:
             if sol.value < ub:
                 ub = sol.value
@@ -110,7 +115,8 @@ def run_gbd(
             g, rhs = feasibility_cut(data, q, sol)
             cuts.append(Cut(kind="feas", c0=rhs, grad=g))
 
-        ms: MasterSolution = solve_master(spec, cuts, use_milp=use_milp)
+        with obs.span("repro.gbd.master", iter=z):
+            ms: MasterSolution = solve_master(spec, cuts, use_milp=use_milp)
         if ms.status != "ok":
             log.warning("master %s at iter %d; stopping with UB=%s", ms.status, z, ub)
             break
@@ -145,6 +151,7 @@ def run_gbd(
         iterations=z,
         converged=converged,
         trace=trace,
+        primal_sweeps=sweeps,
     )
 
 

@@ -85,6 +85,7 @@ class PrimalSolution:
     # Feasibility-cut data (valid when feasible=False):
     tmin_total: float = np.inf
     tmin_grad_q: np.ndarray | None = None  # (N,) d(sum_r t_r^min)/d q_i
+    sweeps: int = 0              # water-filling sweeps this solve ran
 
 
 def _waterfill(alpha1_r, bmin_r, b_max):
@@ -163,8 +164,11 @@ def solve_primal(data: PrimalData, q: np.ndarray) -> PrimalSolution:
             tmin_grad_q=_tmin_gradient(data.alpha2, a, tmin, data.beta2),
         )
 
+    sweeps = 0
+
     def solve_rounds_at(omega3: float):
         """For multiplier omega3, find t_r with sum_i omega2(t_r) = omega3."""
+        nonlocal sweeps
         lo = tmin * (1 + 1e-9)
         # upper bound: with t huge, omega2 -> 0.
         hi = tmin + data.t_max  # generous
@@ -172,6 +176,7 @@ def solve_primal(data: PrimalData, q: np.ndarray) -> PrimalSolution:
             mid = 0.5 * (lo + hi)
             bmin = data.alpha2 / np.maximum(mid[:, None] - a[None, :], 1e-30)
             B, w1 = _waterfill(data.alpha1, bmin, data.b_max)
+            sweeps += 1
             w2sum = _omega2(data.alpha1, data.alpha2, B, w1).sum(axis=1)
             # sum omega2 decreases in t; want it == omega3.
             lo = np.where(w2sum > omega3, mid, lo)
@@ -179,6 +184,7 @@ def solve_primal(data: PrimalData, q: np.ndarray) -> PrimalSolution:
         t = 0.5 * (lo + hi)
         bmin = data.alpha2 / np.maximum(t[:, None] - a[None, :], 1e-30)
         B, w1 = _waterfill(data.alpha1, bmin, data.b_max)
+        sweeps += 1
         return t, B, w1
 
     # Outer bisection on omega3 >= 0 so that sum_r t_r = T_max.
@@ -212,6 +218,7 @@ def solve_primal(data: PrimalData, q: np.ndarray) -> PrimalSolution:
         comp_energy=comp_e, bandwidth=B, t_rounds=t, omega1=w1, omega2=w2,
         omega3=omega3, tmin_total=tmin_total,
         tmin_grad_q=_tmin_gradient(data.alpha2, a, tmin, data.beta2),
+        sweeps=sweeps + 1,
     )
 
 

@@ -26,6 +26,7 @@ from typing import Callable
 
 import numpy as np
 
+from repro import obs
 from repro.api.precision import PrecisionPolicy
 from repro.api.program import Observation, PrecisionProgram, build_program
 from repro.ckpt import CheckpointManager
@@ -38,7 +39,7 @@ from repro.core.energy import (
     alpha_coefficients,
     reference_rate_bps,
 )
-from repro.core.gbd import run_gbd
+from repro.core.gbd import GBDResult, run_gbd
 from repro.core.master import MasterSpec
 from repro.core.primal import PrimalData
 from repro.faults import FaultPlan, UpdateFaults, transmit_update
@@ -146,36 +147,46 @@ class FLOrchestrator:
         for drift-triggered mid-cadence re-solves, where the previous
         assignment is usually near-optimal for the perturbed channel.
         """
+        with obs.span("repro.fl.resolve", round=round_idx,
+                      warm=bool(warm)) as sp:
+            res = self._solve(round_idx, warm, gains0)
+            sp.set(primal_sweeps=res.primal_sweeps)
+            # The solver's chosen bits enter the stack ONLY as a
+            # PrecisionPolicy: the same object the trainer's traced delta and
+            # the serving packer consume (per-device heterogeneous weights
+            # role).
+            policy = PrecisionPolicy.from_gbd(
+                res, comm=self.cfg.precision.comm,
+                kv_cache=self.cfg.precision.kv_cache,
+                bit_options=self.cfg.precision.bit_options)
+            self._strategy = {"policy": policy,
+                              "q": policy.bits_vector(self.cfg.n_devices),
+                              "bandwidth": res.bandwidth,
+                              "t_rounds": res.t_rounds,
+                              "energy_plan": res.energy,
+                              "resolved_at": round_idx,
+                              "gains0": (gains0 if gains0 is not None
+                                         else self.channel.gains(round_idx)),
+                              "warm": bool(warm)}
+        return self._strategy
+
+    def _solve(self, round_idx: int, warm: bool,
+               gains0: np.ndarray | None) -> GBDResult:
         data = self._primal_data(round_idx, gains0)
         scheme = self.cfg.scheme
         if scheme == "fwq":
             q0 = (self._strategy["q"] if warm and self._strategy is not None
                   else None)
-            res = run_gbd(data, self.spec, max_rounds=30, q0=q0)
-        elif scheme == "full_precision":
-            res = baselines_mod.full_precision(data, self.spec)
-        elif scheme == "unified_q":
-            res = baselines_mod.unified_q(data, self.spec, bits=self.cfg.unified_bits)
-        elif scheme == "rand_q":
-            res = baselines_mod.rand_q(data, self.spec, seed=self.cfg.seed + round_idx)
-        else:
-            raise ValueError(scheme)
-        # The solver's chosen bits enter the stack ONLY as a PrecisionPolicy:
-        # the same object the trainer's traced delta and the serving packer
-        # consume (per-device heterogeneous weights role).
-        policy = PrecisionPolicy.from_gbd(
-            res, comm=self.cfg.precision.comm,
-            kv_cache=self.cfg.precision.kv_cache,
-            bit_options=self.cfg.precision.bit_options)
-        self._strategy = {"policy": policy,
-                          "q": policy.bits_vector(self.cfg.n_devices),
-                          "bandwidth": res.bandwidth,
-                          "t_rounds": res.t_rounds, "energy_plan": res.energy,
-                          "resolved_at": round_idx,
-                          "gains0": (gains0 if gains0 is not None
-                                     else self.channel.gains(round_idx)),
-                          "warm": bool(warm)}
-        return self._strategy
+            return run_gbd(data, self.spec, max_rounds=30, q0=q0)
+        if scheme == "full_precision":
+            return baselines_mod.full_precision(data, self.spec)
+        if scheme == "unified_q":
+            return baselines_mod.unified_q(data, self.spec,
+                                           bits=self.cfg.unified_bits)
+        if scheme == "rand_q":
+            return baselines_mod.rand_q(data, self.spec,
+                                        seed=self.cfg.seed + round_idx)
+        raise ValueError(scheme)
 
     def observe(self, round_idx: int, drift: float = 0.0) -> Observation:
         """The measured state the precision program decides from."""
@@ -202,6 +213,10 @@ class FLOrchestrator:
         ``e_comp = p_comp (beta1 + beta2 q)`` bookkeeping the static path
         uses.  The default constant program returns the proposal unchanged.
         """
+        with obs.span("repro.fl.plan", round=round_idx):
+            return self._plan_round(round_idx)
+
+    def _plan_round(self, round_idx: int) -> dict:
         rf = (self.faults.round_faults(round_idx)
               if self.faults is not None else None)
         gains = self.channel.gains(round_idx)
@@ -261,6 +276,7 @@ class FLOrchestrator:
                 "energy_round": float(np.sum(np.where(cohort, e_comp + e_comm, 0.0))),
                 "cohort": cohort, "n_stragglers": int((~on_time).sum()),
                 "n_failed": int((~alive).sum()),
+                "resolved": resolved,
             }
         else:
             rec = self._execute_faulty_round(

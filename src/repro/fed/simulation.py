@@ -14,6 +14,7 @@ from typing import Callable
 import jax
 import numpy as np
 
+from repro import obs
 from repro.core.fwq import (
     FWQConfig,
     delta_for_clients,
@@ -48,9 +49,9 @@ class FLSimulation:
         self.params = init_fn(key)
         self.opt_state = self.opt.init(self.params)
         client_loss = make_tree_quant_loss(loss_fn)
-        round_fn = make_fwq_round(client_loss, self.opt.update,
+        fl_round = make_fwq_round(client_loss, self.opt.update,
                                   FWQConfig(n_clients=cfg.n_clients))
-        self._round = jax.jit(round_fn)
+        self._round = jax.jit(fl_round)
         self._client_loss = client_loss
         self._gated = None  # (grads_fn, apply_fn) — built on first fault use
         self.round_idx = 0
@@ -81,10 +82,15 @@ class FLSimulation:
         simulator's math — the vmap round aggregates in full precision per
         Algorithm 1, wire compression is the pod trainer's concern.
         """
+        n = jax.tree_util.tree_leaves(batch)[0].shape[0]
+        with obs.span("repro.sim.round", round=self.round_idx, cohort=n):
+            return self._run_round(batch, bits, n, faults, comm_bits)
+
+    def _run_round(self, batch, bits, n: int, faults: UpdateFaults | None,
+                   comm_bits: int | None) -> dict:
         if hasattr(bits, "bits_vector"):  # PrecisionPolicy
             if comm_bits is None:
                 comm_bits = int(bits.comm)
-            n = jax.tree_util.tree_leaves(batch)[0].shape[0]
             if bits.heterogeneous and len(bits.weights) != n:
                 # a device-indexed policy cannot be positionally mapped onto
                 # an elastic sub-cohort: the caller must select the cohort's
@@ -97,15 +103,17 @@ class FLSimulation:
         delta = delta_for_clients(np.asarray(bits))
         rng = jax.random.fold_in(jax.random.PRNGKey(self.cfg.seed), self.round_idx)
         if faults is None:
-            self.params, self.opt_state, m = self._round(
-                self.params, self.opt_state, batch, delta, rng)
-            rec = {
-                "round": self.round_idx,
-                "loss": float(m.loss),
-                "grad_norm_sq": float(m.grad_norm_sq),
-                "client_loss": np.asarray(m.client_loss),
-                "bits": np.asarray(bits).copy(),
-            }
+            with obs.span("repro.sim.dispatch"):
+                self.params, self.opt_state, m = self._round(
+                    self.params, self.opt_state, batch, delta, rng)
+            with obs.span("repro.sim.wait"):
+                rec = {
+                    "round": self.round_idx,
+                    "loss": float(m.loss),
+                    "grad_norm_sq": float(m.grad_norm_sq),
+                    "client_loss": np.asarray(m.client_loss),
+                    "bits": np.asarray(bits).copy(),
+                }
         else:
             rec = self._run_gated_round(batch, delta, rng, bits, faults)
         if comm_bits is not None:

@@ -13,7 +13,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any
+from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
@@ -214,6 +214,9 @@ class ServeStep:
     fn: Any
     param_specs: Any
     cache_specs: Any
+    # the number of input signatures the program has traced and loaded (its
+    # jit cache's size); it stays with the step where a caller wraps ``fn``
+    loads: Callable[[], int]
     param_shapes: Any = None
     caches_shape: Any = None
 
@@ -263,7 +266,7 @@ def build_decode_step(model: Model, mesh, axes: AxisCtx, *,
     from repro.models.transformer import padded_vocab_local
     vl = padded_vocab_local(cfg, tp)
 
-    def local_decode(params, batch, caches):
+    def serve_decode(params, batch, caches):
         pc = ParamCtx.from_policy(axes, policy,
                                   compute_dtype=_compute_dtype(cfg))
         logits, new_caches = model.decode_step(pc, params, batch, caches,
@@ -283,14 +286,16 @@ def build_decode_step(model: Model, mesh, axes: AxisCtx, *,
     c_specs = cache_specs(caches_shape, axes, cfg)
     bspec_tree = model.decode_batch_spec(batch_global, s_max)
     bspecs = batch_specs(bspec_tree, axes)
-    sm = jax.shard_map(local_decode, mesh=mesh,
+    sm = jax.shard_map(serve_decode, mesh=mesh,
                        in_specs=(param_specs, bspecs, c_specs),
                        out_specs=(batch_specs(
                            {"token": jax.ShapeDtypeStruct((batch_global, 1), jnp.int32)},
                            axes)["token"], c_specs),
                        check_vma=False)
-    return ServeStep(fn=jax.jit(sm), param_specs=param_specs, cache_specs=c_specs,
-                     param_shapes=params_tree, caches_shape=caches_shape)
+    fn = jax.jit(sm)
+    return ServeStep(fn=fn, param_specs=param_specs, cache_specs=c_specs,
+                     loads=fn._cache_size, param_shapes=params_tree,
+                     caches_shape=caches_shape)
 
 
 def init_global_caches(model: Model, mesh, axes: AxisCtx, *, s_max: int,
@@ -371,7 +376,7 @@ def build_cached_prefill(model: Model, mesh, axes: AxisCtx, *,
     vl = padded_vocab_local(cfg, tp)
     b_local = batch_global // max(_batch_size(mesh, axes), 1)
 
-    def local_prefill(params, batch, caches, slot_mask, prompt_lens=None):
+    def serve_prefill(params, batch, caches, slot_mask, prompt_lens=None):
         pc = ParamCtx.from_policy(axes, policy,
                                   compute_dtype=_compute_dtype(cfg))
         kw = {"prompt_lens": prompt_lens} if prompt_lens is not None else {}
@@ -411,10 +416,12 @@ def build_cached_prefill(model: Model, mesh, axes: AxisCtx, *,
     if with_logits:                         # vocab-parallel local logits
         out_specs = (tok_spec, P(tok_spec[0], None, axes.model_axis),
                      c_specs)
-    sm = jax.shard_map(local_prefill, mesh=mesh, in_specs=tuple(in_specs),
+    sm = jax.shard_map(serve_prefill, mesh=mesh, in_specs=tuple(in_specs),
                        out_specs=out_specs, check_vma=False)
-    return ServeStep(fn=jax.jit(sm), param_specs=param_specs, cache_specs=c_specs,
-                     param_shapes=params_tree, caches_shape=caches_shape)
+    fn = jax.jit(sm)
+    return ServeStep(fn=fn, param_specs=param_specs, cache_specs=c_specs,
+                     loads=fn._cache_size, param_shapes=params_tree,
+                     caches_shape=caches_shape)
 
 
 def serving_axes(axes: AxisCtx, global_batch: int, mesh) -> AxisCtx:

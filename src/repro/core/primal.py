@@ -8,12 +8,14 @@ For a fixed integer assignment ``q`` the remaining problem (paper Eq. 32-34)
                  sum_r T_r <= T_max,   B > 0
 
 with ``a_i(q) = beta1_i + beta2_i q_i`` (compute time) is convex.  We solve it
-by a three-level dual decomposition, each level a monotone bisection,
-vectorized across rounds:
+by a three-level dual decomposition, vectorized across rounds; the outer
+two levels are monotone bisections, the inner one is solved exactly:
 
   * inner  (omega1_r):  per-round water-filling
         B_{i,r}(w1) = max(Bmin_{i,r}, sqrt(alpha1_{i,r}/w1)),
-        Bmin_{i,r} = alpha2_{i,r}/(t_r - a_i); bisect w1 so sum_i B = B_max.
+        Bmin_{i,r} = alpha2_{i,r}/(t_r - a_i); w1 with sum_i B = B_max is
+        exact: sum_i B is piecewise linear in w1^(-1/2), so sorting the
+        breakpoints Bmin/sqrt(alpha1) finds the piece that crosses B_max.
         (The objective strictly decreases in B so (24) is always active.)
   * middle (t_r): round latency; by the envelope theorem
         dE_r/dt = -sum_i omega2_{i,r}   with
@@ -92,23 +94,38 @@ def _waterfill(alpha1_r, bmin_r, b_max):
     """Per-round bandwidth water-filling, vectorized over rounds.
 
     alpha1_r, bmin_r: (R, N).  Returns (B, omega1): (R,N), (R,).
-    Assumes sum_i bmin < b_max (feasible)."""
+    Assumes sum_i bmin < b_max (feasible).
+
+    With u = omega1^(-1/2), sum_i max(bmin_i, sqrt(alpha1_i) u) is piecewise
+    linear and increasing in u, with breakpoints k_i = bmin_i/sqrt(alpha1_i)
+    (+inf where alpha1_i = 0: such a device is never free).  Sorting the
+    breakpoints gives the sum at each; u solves the linear piece that crosses
+    b_max, with the devices past it held at bmin."""
     # Numerical safety: if sum bmin marginally exceeds b_max (bisection
     # tolerance at t ~= t_min), scale bmin down to fit — the latency slack
     # this introduces is O(bisection tolerance).
     over = bmin_r.sum(axis=1) / b_max
     bmin_r = np.where(over[:, None] > 1.0, bmin_r / over[:, None] * (1 - 1e-12), bmin_r)
-    # omega1 bounds: B(w1)=max(bmin, sqrt(a1/w1)); sum B decreasing in w1.
-    hi = np.max(alpha1_r / np.maximum(bmin_r, 1e-30) ** 2, axis=1)  # all at bmin
-    lo = np.full_like(hi, 1e-30)
-    for _ in range(_BISECT_ITERS):
-        mid = np.sqrt(lo * hi)  # log-space bisection
-        B = np.maximum(bmin_r, np.sqrt(alpha1_r / mid[:, None]))
-        too_big = B.sum(axis=1) > b_max
-        lo = np.where(too_big, mid, lo)
-        hi = np.where(too_big, hi, mid)
-    omega1 = np.sqrt(lo * hi)
-    B = np.maximum(bmin_r, np.sqrt(alpha1_r / omega1[:, None]))
+    s = np.sqrt(alpha1_r)
+    pos = s > 0
+    k = np.where(pos, bmin_r / np.where(pos, s, 1.0), np.inf)
+    rows = np.arange(k.shape[0])[:, None]
+    order = np.argsort(k, axis=1)
+    ks = k[rows, order]
+    ss = np.cumsum(s[rows, order], axis=1)  # sqrt(alpha1) of the free devices
+    bs = bmin_r[rows, order]
+    # bmin held by the devices after each breakpoint (suffix sums, no cancellation)
+    held = np.zeros_like(bs)
+    held[:, :-1] = np.cumsum(bs[:, :0:-1], axis=1)[:, ::-1]
+    fin = np.isfinite(ks)
+    at_k = np.where(fin, np.where(fin, ks, 0.0) * ss + held, np.inf)
+    # last breakpoint whose sum stays within b_max (ties share one sum)
+    j = np.maximum(np.sum(at_k <= b_max, axis=1) - 1, 0)
+    den = ss[rows[:, 0], j]
+    num = b_max - held[rows[:, 0], j]
+    omega1 = (den / num) ** 2            # 0 where every alpha1 is 0
+    u = num / np.where(den > 0, den, np.inf)
+    B = np.maximum(bmin_r, s * u[:, None])
     # Renormalize tiny slack onto unconstrained devices for exactness.
     free = B > bmin_r * (1 + 1e-9)
     slack = b_max - B.sum(axis=1)
